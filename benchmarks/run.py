@@ -917,6 +917,9 @@ def main(argv: list[str] | None = None) -> None:
              "0 keeps the platform's real device count",
     )
     args = parser.parse_args(argv)
+    from repro.compile_cache import place_compilation_cache
+
+    place_compilation_cache()
     if args.fit_thresholds:
         from repro.core import fit_thresholds
 

@@ -1,53 +1,31 @@
-"""Version-portable aliases for jax's distribution APIs.
+"""The distribution APIs under the names the rest of the codebase calls.
 
-The distribution layer targets the modern spellings (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``); older jax
-releases (<= 0.4.x) ship the same functionality under
-``jax.experimental.shard_map`` / positional ``make_mesh`` / the ``Mesh``
-context manager. Routing every call site through this module keeps the rest
-of the codebase on one spelling and makes the distributed paths run on
-whichever jax the container bakes in.
+Every sharded path builds meshes, binds them and maps over them through
+these three names, so the spelling lives in one place:
+``jax.shard_map``, ``jax.make_mesh(..., axis_types=...)`` with explicit
+``Auto`` axes, and ``jax.set_mesh``.
 """
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
 
 def shard_map(f=None, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where available, else the experimental spelling.
-
-    Usable exactly like the modern API: ``shard_map(f, mesh=..., ...)`` or
-    as a partial ``shard_map(mesh=..., ...)(f)``.
-    """
+    """``jax.shard_map``, usable directly or as a partial
+    ``shard_map(mesh=..., in_specs=..., out_specs=...)(f)``."""
     if f is None:
         return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
                                    out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the old checker rejects some valid collective
-    # patterns (gather-then-reduce) that the modern one accepts.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit-Auto axis types when supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis typed ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def use_mesh(mesh):
-    """Context manager binding ``mesh`` for jitted sharded computations:
-    ``jax.set_mesh`` on modern jax, the ``Mesh`` context manager before it.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext() if mesh is None else mesh
+    """Context manager binding ``mesh`` for jitted sharded computations."""
+    return jax.set_mesh(mesh)

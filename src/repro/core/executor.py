@@ -14,7 +14,7 @@ setup, graph analytics with changing weights, now at serving rates.
   * **replay**: ``apply(a_values, b_values)`` is a single jitted dispatch of
     the precomposed v2 plan (two gathers + one sorted segment-sum), with an
     optional donating variant for serving loops that discard their inputs;
-  * **batch**: ``apply_batched`` vmaps the replay over stacked value arrays
+  * **batch**: ``apply_batched`` maps the replay over stacked value arrays
     ``(batch, nnz_cap)`` — same structure, new values, ONE XLA dispatch for
     the whole batch instead of ``batch`` round-trips through the runtime.
 
@@ -32,8 +32,7 @@ accumulator trade-off on the replay hot loop. The Pallas kernels are
 explicit opt-in — not what ``"auto"`` picks — until they have real-TPU
 compile coverage (CI only exercises interpret mode), and they accumulate in
 f32, so f64/int operands route back to XLA. Batched replay always uses the
-XLA path — it is the vmap-friendly formulation, and one fused dispatch is
-the point of batching.
+XLA path — one dispatch for the whole batch is the point of batching.
 """
 from __future__ import annotations
 
@@ -121,30 +120,72 @@ _apply_donated = {
 }
 
 
+def _pallas_replay_fits(plan, a_values, b_values) -> bool:
+    """Do the Pallas replay kernels' VMEM bounds (``kernels.limits``) admit
+    this plan and these value buffers?"""
+    from repro.kernels.limits import replay_misfit  # lazy: kernels dep
+    from repro.kernels.segsum_reuse import VAL_TILE
+
+    def pad(n: int) -> int:
+        return -(-n // VAL_TILE) * VAL_TILE
+
+    return replay_misfit(pad(a_values.shape[-1]), pad(b_values.shape[-1]),
+                         plan.indices.shape[0]) is None
+
+
+def fitting_backend(backend: str, plan, a_values, b_values) -> str:
+    """A selected replay backend, or "xla" when it is a Pallas kernel whose
+    size bound the plan exceeds (a measured winner is recorded per size
+    bucket, so it may come from a smaller problem)."""
+    if backend in ("pallas", "pallas_lp") and not _pallas_replay_fits(
+            plan, a_values, b_values):
+        return "xla"
+    return backend
+
+
 def replay_candidates(plan, a_values, b_values, interpret: bool) -> dict:
     """The eligible replay backends for these operands, as autotuner thunks.
 
     This *is* the PR 5 selection table in measurable form: XLA is always
     eligible; the f32-accumulating Pallas kernels (segsum + LP-hash) join
-    only when ``f32_accumulation_ok`` admits the operand dtypes — measure
-    mode must never time (let alone pick) a kernel the dtype guard would
-    refuse to dispatch.
+    only when ``f32_accumulation_ok`` admits the operand dtypes and the
+    plan fits their size bounds — measure mode must never time (let alone
+    pick) a kernel the dispatch would refuse.
     """
     cands = {"xla": lambda: _apply(plan, a_values, b_values,
                                    backend="xla", interpret=interpret)}
-    if f32_accumulation_ok(a_values.dtype, b_values.dtype):
+    if f32_accumulation_ok(a_values.dtype, b_values.dtype) and \
+            _pallas_replay_fits(plan, a_values, b_values):
         for name in ("pallas", "pallas_lp"):
             cands[name] = (lambda nm=name: _apply(
                 plan, a_values, b_values, backend=nm, interpret=interpret))
     return cands
 
 
+def map_batch(replay, a_values, b_values, a_axis, b_axis):
+    """``replay(av, bv)`` for each element of the stacked operand(s), as one
+    ``lax.map`` loop inside the caller's dispatch.
+
+    ``a_axis``/``b_axis``: 0 for a stacked ``(batch, n)`` operand, None for
+    one shared by every element. A loop rather than ``vmap``: vmapping the
+    gathers and the scatter makes XLA hold every element's f_m products at
+    once, and on a TPU it lays the (f_m, batch) temporary out with the batch
+    as the 128-lane minor dimension, so the replay of a 2^26-product plan at
+    batch 4 needed 32 GiB and did not compile for a 16 GB v5e. Each element
+    is exactly the single replay, so results equal per-call replays bitwise.
+    """
+    if a_axis is None:
+        return jax.lax.map(lambda bv: replay(a_values, bv), b_values)
+    if b_axis is None:
+        return jax.lax.map(lambda av: replay(av, b_values), a_values)
+    return jax.lax.map(lambda ab: replay(*ab), (a_values, b_values))
+
+
 @partial(jax.jit, static_argnames=("a_axis", "b_axis"))
 def _apply_batched(plan, a_values, b_values, a_axis, b_axis):
     _note_trace("executor_apply_batched")
-    return jax.vmap(
-        lambda av, bv: numeric_reuse(plan, av, bv), in_axes=(a_axis, b_axis)
-    )(a_values, b_values)
+    return map_batch(lambda av, bv: numeric_reuse(plan, av, bv),
+                     a_values, b_values, a_axis, b_axis)
 
 
 class ReuseExecutor:
@@ -162,7 +203,7 @@ class ReuseExecutor:
     ``kernel_source`` records the provenance ("static" until the first
     measured apply, then "measured"). Requires ``backend="auto"`` — an
     explicit backend pin and measure mode are contradictory instructions.
-    ``apply_batched`` stays on the XLA vmap formulation regardless: one
+    ``apply_batched`` stays on the XLA formulation regardless: one
     fused dispatch is the point of batching, and the Pallas kernels have no
     batched formulation (module docstring).
 
@@ -285,7 +326,7 @@ class ReuseExecutor:
             winner, _ = autotune.measure_and_record(
                 bkey, replay_candidates(self.plan, a_values, b_values,
                                         self.interpret))
-        self.backend = winner
+        self.backend = fitting_backend(winner, self.plan, a_values, b_values)
         self.kernel_source = "measured"
         self._needs_measure = False
 
@@ -476,7 +517,7 @@ class ReuseExecutor:
             self._guard.check_values(a_values, b_values, self.validate_mode,
                                      batched=True)
         batch = a_values.shape[0] if a_axis == 0 else b_values.shape[0]
-        # batched replay is always the XLA vmap formulation (module docstring)
+        # batched replay is always the XLA formulation (module docstring)
         with obs_trace.span("numeric.dispatch", kernel="xla",
                             site="executor", batch=batch):
             if self.watchdog is None:
@@ -511,7 +552,7 @@ def spgemm_grouped(pairs: Sequence[tuple[CSR, CSR]], *,
     the plan-cache entry's recorded winner when one exists (zero re-tuning
     across calls), else a first-sight measurement whose winner is written
     back to the entry, exactly mirroring ``spgemm(tune="measure")``.
-    Batched (>1) groups keep the XLA vmap formulation — one fused dispatch
+    Batched (>1) groups keep the XLA formulation — one batched dispatch
     is the point of batching (see ReuseExecutor). Requires backend="auto".
     """
     from repro.core import autotune  # lazy, mirrors ReuseExecutor

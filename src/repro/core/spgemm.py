@@ -159,7 +159,8 @@ def _single_sort_order(rows: jax.Array, keys: jax.Array, m: int,
 
     Width selection is static (m, key_bound are trace-time ints): int32
     packing when (m+1)*key_bound fits, int64 when x64 is enabled, otherwise a
-    single fused two-key ``lax.sort`` — still one sort pass, never two.
+    single fused ``lax.sort`` on (rows, keys, index) — still one sort pass,
+    never two.
     ``key_bound=None`` means "unknown at trace time": use the fused sort.
     """
     span = None if key_bound is None else (m + 1) * key_bound  # rows pad to m
@@ -169,11 +170,14 @@ def _single_sort_order(rows: jax.Array, keys: jax.Array, m: int,
     if span is not None and jax.config.jax_enable_x64 and span <= np.iinfo(np.int64).max:
         packed = rows.astype(jnp.int64) * jnp.int64(key_bound) + keys.astype(jnp.int64)
         return jnp.argsort(packed, stable=True).astype(jnp.int32)
+    # the index as a third key makes every key unique, so an unstable sort
+    # gives exactly the stable order; the TPU compiler builds an unstable
+    # three-key sort in about half the time of a stable two-key one
     iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
     _, _, order = jax.lax.sort(
         (rows.astype(jnp.int32), keys.astype(jnp.int32), iota),
-        num_keys=2,
-        is_stable=True,
+        num_keys=3,
+        is_stable=False,
     )
     return order
 
@@ -323,7 +327,7 @@ def symbolic_compressed(a: CSR, bc: CompressedMatrix, m: int, fm_cap: int,
     products, OR the CS masks per (row, CSI), sum popcounts per row.
 
     key_bound: static bound on CSI values (ceil(k/32)) enabling the packed
-    single-key sort; None falls back to the fused two-key sort."""
+    single-key sort; None falls back to the fused multi-key sort."""
     _note_trace("symbolic_compressed")
     bc_row_nnz = bc.row_nnz()
     a_valid = a.valid_mask()
@@ -621,7 +625,7 @@ def _measured_replay(plan, a: CSR, b: CSR, cache, cache_key: str):
     ``spgemm_grouped`` re-dispatch it with zero re-tuning.
     """
     from repro.core import autotune
-    from repro.core.executor import _apply, replay_candidates
+    from repro.core.executor import _apply, fitting_backend, replay_candidates
 
     interp = jax.default_backend() != "tpu"
     meta_key = ("tuned_backend", str(a.values.dtype), str(b.values.dtype))
@@ -638,6 +642,7 @@ def _measured_replay(plan, a: CSR, b: CSR, cache, cache_key: str):
                 bkey, replay_candidates(plan, a.values, b.values, interp))
         if cache is not None:
             cache.set_meta(cache_key, meta_key, winner)
+    winner = fitting_backend(winner, plan, a.values, b.values)
     values = _apply(plan, a.values, b.values, backend=winner,
                     interpret=interp)
     return values, winner

@@ -19,7 +19,7 @@ Value routing is part of the plan, so replays never touch structure:
     *structure* all-gather was hoisted to plan-build time — the per-replay
     collective moves only ``(S, b_cap)`` values, not the CSR triplet.
 
-``apply_batched`` vmaps the per-shard replay over stacked value arrays
+``apply_batched`` maps the per-shard replay over stacked value arrays
 ``(batch, nnz_cap)`` — one dispatch for the whole batch across the whole
 mesh. Replays are bitwise identical to the single-device executor after
 ``merge_shards``: each shard's products are the same products in the same
@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core.distributed import ShardedCSR, merge_shards
-from repro.core.executor import DISPATCH_COUNTS
+from repro.core.executor import DISPATCH_COUNTS, map_batch
 from repro.core.meta import DEFAULT_PAD_POLICY
 from repro.core.plan_cache import structure_key
 from repro.core.spgemm import (
@@ -76,10 +76,8 @@ def _replay_replicated(ip, ix, seg, asl, bsl, aperm, a_values, b_values,
         ap = aperm[0]
         if not batched:
             return numeric_reuse(plan, a_values[ap], b_values)[None]
-        out = jax.vmap(
-            lambda av, bv: numeric_reuse(plan, av[ap], bv),
-            in_axes=(a_axis, b_axis),
-        )(a_values, b_values)
+        out = map_batch(lambda av, bv: numeric_reuse(plan, av[ap], bv),
+                        a_values, b_values, a_axis, b_axis)
         return out[None]  # (1, batch, nnz_cap)
 
     out = shard_map(
@@ -114,10 +112,8 @@ def _replay_allgather(ip, ix, seg, asl, bsl, aperm, bshard, bperm,
             bg = gathered.reshape(-1)[bperm]
         if not batched:
             return numeric_reuse(plan, a_values[ap], bg)[None]
-        out = jax.vmap(
-            lambda av, bv: numeric_reuse(plan, av[ap], bv),
-            in_axes=(a_axis, 0 if b_axis == 0 else None),
-        )(a_values, bg)
+        out = map_batch(lambda av, bv: numeric_reuse(plan, av[ap], bv),
+                        a_values, bg, a_axis, b_axis)
         return out[None]
 
     out = shard_map(

@@ -50,9 +50,12 @@ def pipeline_forward(layer, weights: jax.Array, x: jax.Array, mesh,
             buf = jax.lax.ppermute(out, axis, ring)
             return buf, outs
 
-        buf0 = jnp.zeros_like(x_all[0])
-        _, outs = jax.lax.fori_loop(
-            0, n_mb + n_stages - 1, step, (buf0, jnp.zeros_like(x_all)))
+        # the carry turns device-varying after the first ppermute, so it
+        # starts varying over the pipe axis too
+        carry0 = jax.lax.pcast(
+            (jnp.zeros_like(x_all[0]), jnp.zeros_like(x_all)), (axis,),
+            to="varying")
+        _, outs = jax.lax.fori_loop(0, n_mb + n_stages - 1, step, carry0)
         # results live on the last stage only; psum replicates them
         return jax.lax.psum(
             jnp.where(idx == n_stages - 1, outs, jnp.zeros_like(outs)), axis)

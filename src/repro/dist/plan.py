@@ -28,11 +28,13 @@ The plan also pins the *value routing* so replays never touch structure:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
@@ -100,15 +102,36 @@ def dist_expand_and_sort(a_sh: ShardedCSR, b: CSR | ShardedCSR, mesh,
     host reads its per-shard sums to pick the uniform ``nnz_cap`` bucket,
     then feeds the *same* expansion to the plan build (never re-expanded).
     """
-    m_loc = a_sh.m_loc
-    k = b.shape[1]
-    replicated = isinstance(b, CSR)
+    arrays, static = mesh_expand_args(a_sh, b, mesh, axis, fm_cap)
+    return expand_on_mesh(*arrays, **static)
+
+
+def mesh_expand_args(a_sh: ShardedCSR, b: CSR | ShardedCSR, mesh, axis: str,
+                     fm_cap: int) -> tuple[tuple, dict]:
+    """``expand_on_mesh``'s (arrays, static kwargs) for these operands."""
+    return ((a_sh.indptr, a_sh.indices, a_sh.values, b.indptr, b.indices,
+             b.values),
+            dict(mesh=mesh, axis=axis, fm_cap=fm_cap,
+                 a_shape=tuple(a_sh.shape), b_shape=tuple(b.shape)))
+
+
+@partial(jax.jit, static_argnames=("mesh", "axis", "fm_cap", "a_shape",
+                                   "b_shape"))
+def expand_on_mesh(ip, ix, vl, b_ip, b_ix, b_vl, *, mesh, axis: str,
+                   fm_cap: int, a_shape: tuple, b_shape: tuple):
+    """The jitted program behind ``dist_expand_and_sort``, on raw arrays:
+    A's stacked shards and B either whole (1-D arrays: replicated) or
+    stacked shards (2-D: all-gathered inside). One named program, so a
+    caller can compile it ahead (``expand_on_mesh.lower(...)``)."""
+    m_loc = ip.shape[1] - 1
+    k = b_shape[1]
+    replicated = b_ip.ndim == 1
 
     def fn(ip, ix, vl, b_ip, b_ix, b_vl):
         a_loc = CSR(indptr=ip[0], indices=ix[0], values=vl[0],
-                    shape=(m_loc, a_sh.shape[1]))
+                    shape=(m_loc, a_shape[1]))
         if replicated:
-            b_loc = CSR(indptr=b_ip, indices=b_ix, values=b_vl, shape=b.shape)
+            b_loc = CSR(indptr=b_ip, indices=b_ix, values=b_vl, shape=b_shape)
         else:
             b_ips = jax.lax.all_gather(b_ip[0], axis)
             b_ixs = jax.lax.all_gather(b_ix[0], axis)
@@ -123,7 +146,7 @@ def dist_expand_and_sort(a_sh: ShardedCSR, b: CSR | ShardedCSR, mesh,
         fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)) + b_specs,
         out_specs=out_specs,
-    )(a_sh.indptr, a_sh.indices, a_sh.values, b.indptr, b.indices, b.values)
+    )(ip, ix, vl, b_ip, b_ix, b_vl)
 
 
 def build_sharded_plan(a: CSR, b: CSR, mesh, *, axis: str = "data",
@@ -165,10 +188,15 @@ def build_sharded_plan(a: CSR, b: CSR, mesh, *, axis: str = "data",
         return p.indptr, p.indices, p.seg_ids, p.a_slot_s, p.b_slot_s
 
     ip, ix, seg, asl, bsl = jax.vmap(build)(sx)
+    # pin every plan array on the mesh: per-shard stacks one slab per device,
+    # the value-routing perms that act on whole buffers replicated
+    sharded = NamedSharding(mesh, P(axis))
+    replicated = NamedSharding(mesh, P())
+    ip, ix, seg, asl, bsl, a_perm = jax.device_put(
+        (ip, ix, seg, asl, bsl, a_perm), sharded)
+    b_shard_perm, b_perm = jax.device_put((b_shard_perm, b_perm), replicated)
     return ShardedPlan(
         indptr=ip, indices=ix, seg_ids=seg, a_slot_s=asl, b_slot_s=bsl,
-        a_perm=jnp.asarray(a_perm),
-        b_shard_perm=jnp.asarray(b_shard_perm),
-        b_perm=jnp.asarray(b_perm),
+        a_perm=a_perm, b_shard_perm=b_shard_perm, b_perm=b_perm,
         shape=(a.m, k),
     )

@@ -25,10 +25,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compression import bitmask_rows, flops_stats
-from repro.core.meta import choose_kernel, f32_accumulation_ok
+from repro.core.meta import (DEFAULT_PAD_POLICY, choose_kernel,
+                             f32_accumulation_ok, round_capacity)
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.grouped_matmul import TM, grouped_matmul
+from repro.kernels.limits import ell_misfit
 from repro.kernels.spgemm_lp import spgemm_lp_bucketed
 from repro.kernels.spgemm_numeric import spgemm_numeric_bucketed
 from repro.kernels.spgemm_symbolic import spgemm_symbolic_bucketed
@@ -48,8 +50,25 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _bucketed(widths, pad_policy: str | None) -> tuple[int, ...]:
+    """ELL widths as the bucketed kernel wrappers pad them."""
+    policy = DEFAULT_PAD_POLICY if pad_policy is None else pad_policy
+    return tuple(round_capacity(w, policy) for w in widths)
+
+
+def numeric_kernel_misfit(kname: str, a: CSR, b: CSR,
+                          widths: tuple[int, int, int]) -> str | None:
+    """Why ELL kernel ``kname`` cannot take this problem on the chip (see
+    ``kernels.limits``), or None; the XLA reference always fits."""
+    if kname == "xla":
+        return None
+    r_a, r_b, r_c = widths
+    return ell_misfit(kname, m=a.m, n=b.m, r_a=r_a, r_b=r_b, r_c=r_c, k=b.k)
+
+
 def resolve_numeric_kernel(a: CSR, b: CSR, kernel: str = "auto",
-                           fm: int | None = None) -> str:
+                           fm: int | None = None,
+                           widths: tuple[int, int, int] | None = None) -> str:
     """Resolve ``kernel`` to a concrete numeric-phase implementation.
 
     "auto" applies ``core.meta.choose_kernel`` (the avg-row-flops rule,
@@ -64,6 +83,11 @@ def resolve_numeric_kernel(a: CSR, b: CSR, kernel: str = "auto",
     from ``spgemm`` stats). Computing it here costs an O(nnz) ``flops_stats``
     pass plus a device->host sync per call — replay loops over a pinned
     structure should pass their constant ``fm`` instead of re-paying that.
+
+    "auto" never resolves to a kernel whose size bound (``kernels.limits``)
+    the problem exceeds: such problems resolve to "xla". widths: the
+    bucketed ELL widths (rA, rB, rC) when the caller has them; by default
+    rA/rB come from the operands and rC is taken as 0.
     """
     from repro.core import autotune  # lazy: avoid kernels<->core cycle
 
@@ -88,9 +112,12 @@ def resolve_numeric_kernel(a: CSR, b: CSR, kernel: str = "auto",
         fm = int(flops_stats(a, b.row_nnz())[0])
     measured = autotune.lookup_measured(autotune.bucket_key(
         a.m, b.k, fm, a.values.dtype, b.values.dtype, table="numeric"))
-    if measured is not None:
-        return measured
-    return choose_kernel(a, b, {"fm": fm})
+    pick = measured if measured is not None else choose_kernel(a, b, {"fm": fm})
+    if widths is None:
+        widths = _bucketed(
+            [max(int(np.max(np.diff(np.asarray(x.indptr)))), 1)
+             for x in (a, b)] + [0], None)
+    return "xla" if numeric_kernel_misfit(pick, a, b, widths) else pick
 
 
 def symbolic_rowsizes(a: CSR, b: CSR, *, pad_policy: str | None = None) -> jax.Array:
@@ -177,19 +204,29 @@ def numeric_values(a: CSR, b: CSR, c_idx: jax.Array, c_nnz: jax.Array, *,
     # it up front also prices the ladder's static rung at zero extra passes
     if kernel == "auto" and fm is None:
         fm = int(flops_stats(a, b.row_nnz())[0])
+    widths = _bucketed((ea.indices.shape[1], eb.indices.shape[1],
+                        c_idx.shape[1]), pad_policy)
+
+    def fits(kname: str) -> bool:
+        return numeric_kernel_misfit(kname, a, b, widths) is None
+
     if tune == "measure":
         bkey = autotune.bucket_key(a.m, b.k, fm, a.values.dtype,
                                    b.values.dtype, table="numeric")
         resolved = autotune.lookup_measured(bkey)
+        if resolved is not None and not fits(resolved):
+            resolved = "xla"
         if resolved is None:
             # candidate set = the dtype-eligible rows of the selection table
+            # that fit the chip's kernel bounds
             cands = {"xla": lambda: run("xla")}
             if f32_accumulation_ok(a.values.dtype, b.values.dtype):
-                cands["dense_acc"] = lambda: run("dense_acc")
-                cands["flat_lp"] = lambda: run("flat_lp")
+                for kname in ("dense_acc", "flat_lp"):
+                    if fits(kname):
+                        cands[kname] = lambda kn=kname: run(kn)
             resolved, _ = autotune.measure_and_record(bkey, cands)
     else:
-        resolved = resolve_numeric_kernel(a, b, kernel, fm=fm)
+        resolved = resolve_numeric_kernel(a, b, kernel, fm=fm, widths=widths)
         if (kernel == "auto" and resolved == "xla"
                 and not f32_accumulation_ok(a.values.dtype, b.values.dtype)):
             from repro.core.telemetry import FALLBACK_COUNTS  # lazy: cycle
@@ -201,7 +238,7 @@ def numeric_values(a: CSR, b: CSR, c_idx: jax.Array, c_nnz: jax.Array, *,
     ladder = [resolved]
     if kernel == "auto" or tune == "measure":
         static_pick = choose_kernel(a, b, {"fm": fm})
-        if static_pick not in ladder:
+        if static_pick not in ladder and fits(static_pick):
             ladder.append(static_pick)
     if "xla" not in ladder:
         ladder.append("xla")

@@ -35,11 +35,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.limits import replay_misfit, require_fit
+
 # products per grid step (the f_m tile) and one-hot gather tile width along
 # the value buffers — both MXU-friendly multiples of 128
 FM_TILE = 512
 VAL_TILE = 512
 LANES = 128  # lane-group alignment for the windowed dynamic store
+# f32 passes on the MXU: the one-hot operand is exact in bf16, the values are
+# not, and the default single bf16 pass would round each one to 8 bits
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def _gather_row(val_ref, slots):
@@ -50,14 +55,13 @@ def _gather_row(val_ref, slots):
 
     def body(c, acc):
         base = c * VAL_TILE
-        chunk = pl.load(
-            val_ref, (slice(None), pl.dslice(base, VAL_TILE))
-        ).astype(jnp.float32)  # (1, VAL_TILE)
+        chunk = val_ref[:, pl.ds(base, VAL_TILE)].astype(jnp.float32)  # (1, VAL_TILE)
         onehot = (
             base + jax.lax.broadcasted_iota(jnp.int32, (VAL_TILE, t), 0)
             == slots[None, :]
         ).astype(jnp.float32)  # (VAL_TILE, t)
-        return acc + jnp.dot(chunk, onehot, preferred_element_type=jnp.float32)
+        return acc + jnp.dot(chunk, onehot, precision=EXACT,
+                             preferred_element_type=jnp.float32)
 
     return jax.lax.fori_loop(0, n // VAL_TILE, body, jnp.zeros((1, t), jnp.float32))
 
@@ -87,14 +91,10 @@ def _kernel(a_val_ref, b_val_ref, a_slot_ref, b_slot_ref, seg_ref, out_ref):
     onehot = (
         local[:, None] == jax.lax.broadcasted_iota(jnp.int32, (fm_t, win), 1)
     ).astype(jnp.float32)  # (fm_t, win); masked rows contribute zero
-    window = jnp.dot(prod, onehot, preferred_element_type=jnp.float32)
+    window = jnp.dot(prod, onehot, preferred_element_type=jnp.float32,
+                     precision=EXACT)
 
-    cur = pl.load(out_ref, (slice(None), pl.dslice(base, win)))
-    pl.store(
-        out_ref,
-        (slice(None), pl.dslice(base, win)),
-        cur + window.astype(out_ref.dtype),
-    )
+    out_ref[:, pl.ds(base, win)] += window.astype(out_ref.dtype)
 
 
 def _pad_to(x: jax.Array, size: int, fill=0) -> jax.Array:
@@ -124,6 +124,7 @@ def segsum_reuse_arrays(a_slot_s, b_slot_s, seg_ids, a_values, b_values, *,
     nb = -(-b_values.shape[0] // VAL_TILE) * VAL_TILE
     a_values = _pad_to(a_values, na)[None, :]
     b_values = _pad_to(b_values, nb)[None, :]
+    require_fit(replay_misfit(na, nb, nnz_cap))
 
     grid = (fm_pad // FM_TILE,)
     out = pl.pallas_call(

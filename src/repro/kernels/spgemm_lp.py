@@ -28,7 +28,7 @@ the row's *output*, not the column space. Two kernels share the LP discipline:
     exists to make the accumulator trade-off *measurable* on the replay hot
     loop (``benchmarks.run bench_accumulators``), not to win it everywhere.
 
-Probe-loop totality: the probe is evaluated as a vectorized argmin over probe
+Probe-loop totality: the probe is evaluated as a vectorized minimum over probe
 distance (first empty-or-matching slot in cyclic order), so a full table
 cannot hang the kernel — an unservable insert simply resolves to a rejected
 candidate and spills, mirroring the clamped-cutoff fix in
@@ -48,8 +48,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.accumulators import MAX_OCCUPANCY
+from repro.kernels.limits import ell_misfit, replay_misfit, require_fit
 from repro.kernels.segsum_reuse import LANES, _gather_row, _pad_to
-from repro.kernels.spgemm_numeric import _pad_width
+from repro.kernels.spgemm_numeric import _pad_width, _pick, _row_views
 
 # products per grid step of the LP replay kernel (lane-aligned); its scratch
 # table is 2x this, so in-tile occupancy can never exceed the 50% cutoff
@@ -77,8 +78,10 @@ def _lp_probe(ids: jax.Array, key: jax.Array):
 
     Probing order is increasing cyclic distance from the hash slot, and the
     probe stops at the first empty-or-match slot; that slot is exactly the
-    minimum-distance candidate, so one vectorized argmin replaces the while
-    loop (and is total even when the table has no candidate at all).
+    minimum-distance candidate, so one vectorized min replaces the while
+    loop (and is total even when the table has no candidate at all: the
+    probe then resolves to slot 0, a non-candidate). The min is over
+    distances, not an argmin, because Mosaic reduces indices of f32 only.
     Returns (slot, key_already_present).
     """
     size = ids.shape[0]
@@ -86,7 +89,8 @@ def _lp_probe(ids: jax.Array, key: jax.Array):
     h = key & mask
     dist = (jax.lax.iota(jnp.int32, size) - h) & mask
     cand = (ids == -1) | (ids == key)
-    p = jnp.argmin(jnp.where(cand, dist, size)).astype(jnp.int32)
+    d = jnp.min(jnp.where(cand, dist, size))
+    p = jnp.where(d < size, (h + d) & mask, 0)
     id_at_p = jnp.sum(jnp.where(jax.lax.iota(jnp.int32, size) == p, ids, 0))
     return p, id_at_p == key
 
@@ -121,14 +125,14 @@ def _kernel(a_idx_ref, a_nnz_ref, b_nnz_ref, c_nnz_ref,  # scalar prefetch
         used_ref[0] = 0
 
     live_a = r < a_nnz_ref[i]
-    n_live_b = jnp.where(live_a, b_nnz_ref[a_idx_ref[i, r]], 0)
-    a_val = a_val_ref[0, r].astype(jnp.float32)
+    n_live_b = jnp.where(live_a, b_nnz_ref[a_idx_ref[i * n_r + r]], 0)
+    a_val = _pick(a_val_ref[0, :].astype(jnp.float32), r)
     cols = b_idx_ref[0, :]  # (rB,) — the B row steered by a_idx[i, r]
     prods = a_val * b_val_ref[0, :].astype(jnp.float32)  # (rB,)
 
     def insert(t, used):
-        key = jax.lax.dynamic_index_in_dim(cols, t, keepdims=False)
-        val = jax.lax.dynamic_index_in_dim(prods, t, keepdims=False)
+        key = _pick(cols, t)
+        val = _pick(prods, t)
         ok = t < n_live_b  # padded B slots must not mint phantom keys
         ids1 = l1_ids_ref[0, :]
         p1, found1 = _lp_probe(ids1, key)
@@ -185,6 +189,8 @@ def spgemm_lp(a_idx, a_val, a_nnz, b_idx, b_val, b_nnz, c_idx, c_nnz, *,
         from repro.runtime.validate import SpgemmConfigError  # cycle-free
         raise SpgemmConfigError(
             f"l1_size must be a power of two >= 2; got {l1_size}")
+    require_fit(ell_misfit("flat_lp", m=m, r_a=r_a, n=n, r_b=r_b, r_c=r_c,
+                           l1_size=l1_size))
     s2 = default_l1_size(r_c)  # L2 holds every possible spill (MAXRF)
     out_dtype = jnp.result_type(a_val, b_val)
 
@@ -195,12 +201,17 @@ def spgemm_lp(a_idx, a_val, a_nnz, b_idx, b_val, b_nnz, c_idx, c_nnz, *,
             num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, r_a), lambda i, r, ai, an, bn, cn: (i, 0)),
-                pl.BlockSpec((1, r_b), lambda i, r, ai, an, bn, cn: (ai[i, r], 0)),
-                pl.BlockSpec((1, r_b), lambda i, r, ai, an, bn, cn: (ai[i, r], 0)),
-                pl.BlockSpec((1, r_c), lambda i, r, ai, an, bn, cn: (i, 0)),
+                pl.BlockSpec((None, 1, r_a),
+                             lambda i, r, ai, an, bn, cn: (i, 0, 0)),
+                pl.BlockSpec((None, 1, r_b),
+                             lambda i, r, ai, an, bn, cn: (ai[i * r_a + r], 0, 0)),
+                pl.BlockSpec((None, 1, r_b),
+                             lambda i, r, ai, an, bn, cn: (ai[i * r_a + r], 0, 0)),
+                pl.BlockSpec((None, 1, r_c),
+                             lambda i, r, ai, an, bn, cn: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, r_c), lambda i, r, ai, an, bn, cn: (i, 0)),
+            out_specs=pl.BlockSpec((None, 1, r_c),
+                                   lambda i, r, ai, an, bn, cn: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, l1_size), jnp.int32),
                 pltpu.VMEM((1, l1_size), jnp.float32),
@@ -209,10 +220,10 @@ def spgemm_lp(a_idx, a_val, a_nnz, b_idx, b_val, b_nnz, c_idx, c_nnz, *,
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, r_c), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, r_c), out_dtype),
         interpret=interpret,
-    )(a_idx, a_nnz, b_nnz, c_nnz, a_val, b_idx, b_val, c_idx)
-    return out
+    )(a_idx.reshape(-1), a_nnz, b_nnz, c_nnz, *_row_views(a_val, b_idx, b_val, c_idx))
+    return out[:, 0, :]
 
 
 def spgemm_lp_bucketed(a_idx, a_val, a_nnz, b_idx, b_val, b_nnz, c_idx, c_nnz,
@@ -270,9 +281,9 @@ def _reuse_kernel(a_val_ref, b_val_ref, a_slot_ref, b_slot_ref, seg_ref,
     prod_v = prod[0, :]
 
     def insert(t, _):
-        key = jax.lax.dynamic_index_in_dim(local, t, keepdims=False)
-        val = jax.lax.dynamic_index_in_dim(prod_v, t, keepdims=False)
-        ok = jax.lax.dynamic_index_in_dim(live, t, keepdims=False)
+        key = _pick(local, t)
+        val = _pick(prod_v, t)
+        ok = _pick(live.astype(jnp.int32), t) > 0
         ids = ids_ref[0, :]
         p, _found = _lp_probe(ids, key)
         # table is 2x the tile: distinct keys <= fm_t == the 50% cutoff, so
@@ -290,12 +301,7 @@ def _reuse_kernel(a_val_ref, b_val_ref, a_slot_ref, b_slot_ref, seg_ref,
     )  # (s1, win); empty slots (-1) match nothing
     window = jnp.sum(jnp.where(eq, val_ref[0, :][:, None], 0.0), axis=0)[None, :]
 
-    cur = pl.load(out_ref, (slice(None), pl.dslice(base, win)))
-    pl.store(
-        out_ref,
-        (slice(None), pl.dslice(base, win)),
-        cur + window.astype(out_ref.dtype),
-    )
+    out_ref[:, pl.ds(base, win)] += window.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz_cap", "interpret"))
@@ -319,6 +325,7 @@ def lp_reuse_arrays(a_slot_s, b_slot_s, seg_ids, a_values, b_values, *,
     nb = -(-b_values.shape[0] // VAL_TILE) * VAL_TILE
     a_values = _pad_to(a_values, na)[None, :]
     b_values = _pad_to(b_values, nb)[None, :]
+    require_fit(replay_misfit(na, nb, nnz_cap))
 
     s1 = _next_pow2(2 * LP_TILE)
     grid = (fm_pad // LP_TILE,)

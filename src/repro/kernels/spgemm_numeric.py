@@ -22,8 +22,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.limits import ell_misfit, require_fit
+
 # one-hot scatter tile width along the dense-accumulator (column) axis
 K_TILE = 512
+# the one-hot operand is exact in bf16 but the values are not: the MXU's
+# default single bf16 pass would round every product to 8 mantissa bits
+EXACT = jax.lax.Precision.HIGHEST
+
+
+def _row_views(*arrays):
+    """(rows, r) -> (rows, 1, r): Mosaic tiles the last two block dims, so a
+    one-row block of an ELL array must be a whole (1, r) trailing slab."""
+    return tuple(x[:, None, :] for x in arrays)
+
+
+def _pick(vec: jax.Array, t) -> jax.Array:
+    """``vec[t]`` for a dynamic ``t`` as a masked sum: Mosaic lowers neither
+    a dynamic-lane scalar load nor ``dynamic_slice`` on a vector value."""
+    hit = jax.lax.iota(jnp.int32, vec.shape[0]) == t
+    return jnp.sum(jnp.where(hit, vec, jnp.zeros_like(vec)))
 
 
 def _kernel(a_idx_ref, a_nnz_ref, c_nnz_ref,  # scalar prefetch
@@ -34,7 +52,6 @@ def _kernel(a_idx_ref, a_nnz_ref, c_nnz_ref,  # scalar prefetch
     r = pl.program_id(1)
     n_r = pl.num_programs(1)
     k_pad = acc_ref.shape[1]
-    r_b = b_idx_ref.shape[1]
     r_c = out_ref.shape[1]
 
     @pl.when(r == 0)
@@ -42,7 +59,7 @@ def _kernel(a_idx_ref, a_nnz_ref, c_nnz_ref,  # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     live = r < a_nnz_ref[i]
-    a_val = jnp.where(live, a_val_ref[0, r], 0.0)
+    a_val = jnp.where(live, _pick(a_val_ref[0, :].astype(jnp.float32), r), 0.0)
     cols = b_idx_ref[0, :]  # (rB,)
     scaled = (a_val * b_val_ref[0, :].astype(jnp.float32))[None, :]  # (1, rB)
 
@@ -52,9 +69,9 @@ def _kernel(a_idx_ref, a_nnz_ref, c_nnz_ref,  # scalar prefetch
         onehot = (
             cols[:, None] == base + jax.lax.iota(jnp.int32, K_TILE)[None, :]
         ).astype(jnp.float32)
-        tile = jnp.dot(scaled, onehot, preferred_element_type=jnp.float32)
-        cur = pl.load(acc_ref, (slice(None), pl.dslice(base, K_TILE)))
-        pl.store(acc_ref, (slice(None), pl.dslice(base, K_TILE)), cur + tile)
+        tile = jnp.dot(scaled, onehot, preferred_element_type=jnp.float32,
+                       precision=EXACT)
+        acc_ref[:, pl.ds(base, K_TILE)] += tile
         return 0
 
     jax.lax.fori_loop(0, k_pad // K_TILE, scatter_tile, 0)
@@ -68,8 +85,9 @@ def _kernel(a_idx_ref, a_nnz_ref, c_nnz_ref,  # scalar prefetch
             onehot = (
                 base + jax.lax.iota(jnp.int32, K_TILE)[:, None] == c_cols[None, :]
             ).astype(jnp.float32)  # (K_TILE, rC)
-            seg = pl.load(acc_ref, (slice(None), pl.dslice(base, K_TILE)))
-            return out + jnp.dot(seg, onehot, preferred_element_type=jnp.float32)
+            seg = acc_ref[:, pl.ds(base, K_TILE)]
+            return out + jnp.dot(seg, onehot, precision=EXACT,
+                                 preferred_element_type=jnp.float32)
 
         vals = jax.lax.fori_loop(
             0, k_pad // K_TILE, gather_tile, jnp.zeros((1, r_c), jnp.float32)
@@ -90,6 +108,7 @@ def spgemm_numeric(a_idx, a_val, a_nnz, b_idx, b_val, c_idx, c_nnz, *,
     m, r_a = a_idx.shape
     n, r_b = b_idx.shape
     r_c = c_idx.shape[1]
+    require_fit(ell_misfit("dense_acc", m=m, r_a=r_a, r_b=r_b, r_c=r_c, k=k))
     k_pad = -(-k // K_TILE) * K_TILE
 
     grid = (m, r_a)
@@ -99,18 +118,21 @@ def spgemm_numeric(a_idx, a_val, a_nnz, b_idx, b_val, c_idx, c_nnz, *,
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, r_a), lambda i, r, ai, an, cn: (i, 0)),
-                pl.BlockSpec((1, r_b), lambda i, r, ai, an, cn: (ai[i, r], 0)),
-                pl.BlockSpec((1, r_b), lambda i, r, ai, an, cn: (ai[i, r], 0)),
-                pl.BlockSpec((1, r_c), lambda i, r, ai, an, cn: (i, 0)),
+                pl.BlockSpec((None, 1, r_a), lambda i, r, ai, an, cn: (i, 0, 0)),
+                pl.BlockSpec((None, 1, r_b),
+                             lambda i, r, ai, an, cn: (ai[i * r_a + r], 0, 0)),
+                pl.BlockSpec((None, 1, r_b),
+                             lambda i, r, ai, an, cn: (ai[i * r_a + r], 0, 0)),
+                pl.BlockSpec((None, 1, r_c), lambda i, r, ai, an, cn: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, r_c), lambda i, r, ai, an, cn: (i, 0)),
+            out_specs=pl.BlockSpec((None, 1, r_c),
+                                   lambda i, r, ai, an, cn: (i, 0, 0)),
             scratch_shapes=[pltpu.VMEM((1, k_pad), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, r_c), a_val.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, r_c), a_val.dtype),
         interpret=interpret,
-    )(a_idx, a_nnz, c_nnz, a_val, b_idx, b_val, c_idx)
-    return out
+    )(a_idx.reshape(-1), a_nnz, c_nnz, *_row_views(a_val, b_idx, b_val, c_idx))
+    return out[:, 0, :]
 
 
 def _pad_width(x: jax.Array, width: int) -> jax.Array:

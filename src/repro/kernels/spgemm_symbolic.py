@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.limits import ell_misfit, require_fit
+
 
 def _kernel(a_idx_ref, a_nnz_ref, b_bm_ref, out_ref, acc_ref):
     i = pl.program_id(0)
@@ -38,7 +40,8 @@ def _kernel(a_idx_ref, a_nnz_ref, b_bm_ref, out_ref, acc_ref):
     @pl.when(r == n_r - 1)
     def _emit():
         counts = jax.lax.population_count(acc_ref[...])
-        out_ref[0, 0] = jnp.sum(counts.astype(jnp.int32))
+        out_ref[...] = jnp.full(out_ref.shape,
+                                jnp.sum(counts.astype(jnp.int32)), jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -57,6 +60,7 @@ def spgemm_symbolic(a_idx: jax.Array, a_nnz: jax.Array, b_bitmask: jax.Array,
         from repro.runtime.validate import SpgemmInputError  # cycle-free
         raise SpgemmInputError(
             f"k32={k32} must be lane-aligned (multiple of 128)")
+    require_fit(ell_misfit("symbolic", m=m, r_a=r_a, k=32 * k32))
 
     grid = (m, r_a)
     out = pl.pallas_call(
@@ -66,17 +70,18 @@ def spgemm_symbolic(a_idx: jax.Array, a_nnz: jax.Array, b_bitmask: jax.Array,
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (1, k32),
-                    lambda i, r, a_idx, a_nnz: (a_idx[i, r], 0),
+                    (None, 1, k32),
+                    lambda i, r, a_idx, a_nnz: (a_idx[i * r_a + r], 0, 0),
                 ),
             ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, r, a_idx, a_nnz: (i, 0)),
+            out_specs=pl.BlockSpec((None, 1, 1),
+                                   lambda i, r, a_idx, a_nnz: (i, 0, 0)),
             scratch_shapes=[pltpu.VMEM((1, k32), jnp.uint32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((m, 1, 1), jnp.int32),
         interpret=interpret,
-    )(a_idx, a_nnz, b_bitmask)
-    return out[:, 0]
+    )(a_idx.reshape(-1), a_nnz, b_bitmask[:, None, :])
+    return out[:, 0, 0]
 
 
 def spgemm_symbolic_bucketed(a_idx: jax.Array, a_nnz: jax.Array,

@@ -30,7 +30,7 @@ at and past saturation*:
     ``fault:*`` fallbacks open the breaker and subsequent singleton traffic
     routes straight to the recorded-safe XLA kernel; after a cooldown a
     half-open probe re-admits the fast path. Transitions land in
-    ``telemetry.BREAKER_COUNTS``. Batched groups always use the vmapped XLA
+    ``telemetry.BREAKER_COUNTS``. Batched groups always use the batched XLA
     formulation (one fused dispatch is the point of batching), so the
     breaker governs singleton dispatches only.
   * **Watchdog + retry.** Every group dispatch runs under a shared
@@ -140,7 +140,7 @@ class SparseService:
     backend: the fast replay path for singleton dispatches ("auto" resolves
         to "xla"; "pallas"/"pallas_lp" opt into the Pallas kernels, guarded
         by a per-kernel circuit breaker). Batched groups always take the
-        vmapped XLA formulation.
+        batched XLA formulation.
     validate: admission-time operand validation mode (default "host" — the
         serving tier rejects corruption at the door; "off" is the caller's
         risk).

@@ -266,7 +266,10 @@ def test_service_chaos_under_traffic():
     """Live traffic through SparseService while everything misbehaves at
     once — kernel failpoints flapping, one corrupt request in the stream, a
     forced plan-cache eviction mid-stream. The bar is the failure model's:
-    every COMPLETED response is bitwise-equal to the XLA reference and every
+    every COMPLETED response is bitwise-equal to the reference of the path
+    that served it — the XLA reference for XLA, batched and degraded
+    dispatches, the healthy kernel's own replay for fast-path dispatches
+    (its f32 window sums round differently from XLA's scatter) — and every
     non-completion is a typed SpgemmError; nothing silent, nothing dropped.
     """
     from repro.serve import SparseService
@@ -277,7 +280,13 @@ def test_service_chaos_under_traffic():
         (random_csr(16, 24, 3.0, seed=7), random_csr(24, 8, 3.0, seed=8)),
         (random_csr(48, 16, 2.0, seed=9), random_csr(16, 48, 3.0, seed=10)),
     ]
-    refs = [spgemm(a, b, method="sparse").c.to_dense() for a, b in structures]
+    refs, fast_refs = [], []
+    for a, b in structures:
+        res = spgemm(a, b, method="sparse")
+        refs.append(res.c.to_dense())
+        fast = ReuseExecutor(res.plan, backend="pallas",
+                             on_kernel_failure="raise")
+        fast_refs.append(fast.to_csr(fast.apply(a.values, b.values)).to_dense())
     svc = SparseService(backend="pallas", max_batch=2, breaker_threshold=2,
                         retries=1, sleep=lambda _: None)
     ledger = []  # (response, reference | None for the corrupt one)
@@ -286,7 +295,7 @@ def test_service_chaos_under_traffic():
         a, b = structures[i % len(structures)]
         if corrupt:
             a = faults.inject_csr("nan_values", a)
-        ledger.append((svc.submit(a, b), None if corrupt else refs[i % 3]))
+        ledger.append((svc.submit(a, b), None if corrupt else i % 3))
 
     for i in range(4):  # clean warm-up traffic
         pump(i)
@@ -306,13 +315,15 @@ def test_service_chaos_under_traffic():
 
     assert len(ledger) == 15
     completed = rejected = 0
-    for resp, ref in ledger:
+    for resp, which in ledger:
         assert resp.done  # nothing silently dropped
-        if ref is None:  # the corrupt request: typed rejection at the door
+        if which is None:  # the corrupt request: typed rejection at the door
             assert isinstance(resp.error, SpgemmInputError)
             rejected += 1
         else:
             assert resp.ok, f"unexpected failure: {resp.error!r}"
+            served_fast = resp.backend == "pallas" and not resp.degraded
+            ref = (fast_refs if served_fast else refs)[which]
             assert bool(jnp.all(resp.value.to_dense() == ref))  # bitwise
             completed += 1
     assert completed == 14 and rejected == 1

@@ -159,3 +159,51 @@ def test_flash_attention_bf16():
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=5e-2, atol=5e-2,
     )
+
+
+def _dot_precisions(jaxpr):
+    """Precision of every dot_general in ``jaxpr`` and its sub-jaxprs (the
+    pallas_call kernel body and its loops included)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_dot_precisions(sub))
+    return out
+
+
+def _ell_operands(m=8, r_a=4, n=8, r_b=8, r_c=16):
+    i32, f32 = jnp.int32, jnp.float32
+    return (jnp.zeros((m, r_a), i32), jnp.zeros((m, r_a), f32),
+            jnp.zeros((m,), i32), jnp.zeros((n, r_b), i32),
+            jnp.zeros((n, r_b), f32), jnp.zeros((m, r_c), i32),
+            jnp.zeros((m,), i32))
+
+
+def _replay_operands(fm=1024, na=512, nb=512):
+    i32, f32 = jnp.int32, jnp.float32
+    return (jnp.zeros((fm,), i32), jnp.zeros((fm,), i32),
+            jnp.zeros((fm,), i32), jnp.zeros((na,), f32),
+            jnp.zeros((nb,), f32))
+
+
+@pytest.mark.parametrize("name", ["dense_acc", "segsum_reuse", "lp_reuse"])
+def test_onehot_matmuls_keep_f32_values(name):
+    """The one-hot scatter/gather matmuls carry f32 values: on the MXU the
+    default precision is one bf16 pass, which rounds each value to 8
+    mantissa bits, so every such dot must ask for HIGHEST."""
+    from repro.kernels.segsum_reuse import segsum_reuse_arrays
+    from repro.kernels.spgemm_lp import lp_reuse_arrays
+
+    fn, args, static = {
+        "dense_acc": (spgemm_numeric, _ell_operands(), {"k": 1024}),
+        "segsum_reuse": (segsum_reuse_arrays, _replay_operands(),
+                         {"nnz_cap": 256}),
+        "lp_reuse": (lp_reuse_arrays, _replay_operands(), {"nnz_cap": 256}),
+    }[name]
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, interpret=True, **static))(*args)
+    found = _dot_precisions(jaxpr.jaxpr)
+    assert found, "no one-hot matmul found"
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    assert all(p == highest for p in found), found
