@@ -19,8 +19,8 @@ whole contract on CPU:
   5. warming — the service's own traffic log prefetches the hot plans after
      an eviction, so the next burst never pays a plan build
   6. observability — turn tracing on for a burst: request trace ids ride
-     every span into a Chrome trace export, per-phase latency histograms
-     land in the metrics registry, and ``stats(debug=True)`` returns the
+     every span into a Chrome trace export, the service's ``serve.request``
+     latency histogram gives p50/p99, and ``stats(debug=True)`` returns the
      flight-recorder ring (tracing off costs nothing — see
      ``benchmarks.run --bench obs``)
 
@@ -119,7 +119,7 @@ def main():
     print(f"5. warmed {stats['built']} plans from the traffic log; the next "
           f"burst ran with zero plan-cache misses")
 
-    # 6. observability: trace a burst, read the histograms, dump the ring --
+    # 6. observability: trace a burst, read request latency, dump the ring -
     obs.set_tracing("on")  # or REPRO_TRACE=1, or spgemm(..., trace=True)
     traced = [svc.submit(*structures[i % 2]) for i in range(4)]
     svc.drain()
@@ -128,12 +128,12 @@ def main():
     spans = payload["traceEvents"]
     tids = sorted({e["args"].get("trace_id") for e in spans
                    if e["args"].get("trace_id")})
-    hist = obs.default_registry().histogram("numeric.dispatch")
     debug = svc.stats(debug=True)
+    req = debug["request_latency"]
     print(f"6. traced burst: {len(spans)} spans from requests {tids} -> "
           f"trace_serve_quickstart.json (open in chrome://tracing); "
-          f"numeric.dispatch p50={hist.percentile(50)*1e6:.0f}us "
-          f"p99={hist.percentile(99)*1e6:.0f}us over {hist.count} dispatches; "
+          f"serve.request p50={req['p50']*1e6:.0f}us "
+          f"p99={req['p99']*1e6:.0f}us over {req['count']} requests; "
           f"flight recorder holds {debug['flight_recorder']['recorded']} "
           f"events")
     obs.set_tracing(None)  # back to the $REPRO_TRACE default (off)

@@ -226,22 +226,27 @@ def expand_and_sort(a: CSR, b: CSR, fm_cap: int) -> SortedExpansion:
     the numeric plan.
     """
     _note_trace("expand_and_sort")
-    ex = expand_products(a, b, fm_cap)
-    order = _single_sort_order(ex.row, ex.col, a.m, b.k)
-    rows_s = ex.row[order]
-    cols_s = ex.col[order]
-    valid_s = ex.valid[order]
-    heads = jnp.concatenate(
-        [
-            jnp.ones((1,), jnp.bool_),
-            (rows_s[1:] != rows_s[:-1]) | (cols_s[1:] != cols_s[:-1]),
-        ]
-    )
-    heads = heads & valid_s  # padding (row==m) groups don't mint slots
-    seg_ids = (jnp.cumsum(heads.astype(jnp.int32)) - 1).clip(0).astype(jnp.int32)
-    row_sizes = jnp.zeros((a.m,), jnp.int32).at[jnp.minimum(rows_s, a.m - 1)].add(
-        heads.astype(jnp.int32), mode="drop"
-    )
+    # the named scopes mark each phase's device ops in a profile (their
+    # ``tf_op`` path); they change op metadata only, never the program
+    with jax.named_scope("spgemm_expand"):
+        ex = expand_products(a, b, fm_cap)
+    with jax.named_scope("spgemm_sort"):
+        order = _single_sort_order(ex.row, ex.col, a.m, b.k)
+    with jax.named_scope("spgemm_permute"):
+        rows_s = ex.row[order]
+        cols_s = ex.col[order]
+        valid_s = ex.valid[order]
+        heads = jnp.concatenate(
+            [
+                jnp.ones((1,), jnp.bool_),
+                (rows_s[1:] != rows_s[:-1]) | (cols_s[1:] != cols_s[:-1]),
+            ]
+        )
+        heads = heads & valid_s  # padding (row==m) groups don't mint slots
+        seg_ids = (jnp.cumsum(heads.astype(jnp.int32)) - 1).clip(0).astype(jnp.int32)
+        row_sizes = jnp.zeros((a.m,), jnp.int32).at[jnp.minimum(rows_s, a.m - 1)].add(
+            heads.astype(jnp.int32), mode="drop"
+        )
     return SortedExpansion(
         order=order,
         rows_s=rows_s,
@@ -425,12 +430,14 @@ def numeric_reuse(plan: SpgemmPlan, a_values: jax.Array, b_values: jax.Array) ->
     """
     _note_trace("numeric_reuse")
     acc_dtype = jnp.result_type(a_values, b_values)
-    prod = (a_values[plan.a_slot_s].astype(acc_dtype)
-            * b_values[plan.b_slot_s].astype(acc_dtype))
+    with jax.named_scope("replay_gather"):
+        prod = (a_values[plan.a_slot_s].astype(acc_dtype)
+                * b_values[plan.b_slot_s].astype(acc_dtype))
     nnz_cap = plan.indices.shape[0]
-    return jnp.zeros((nnz_cap,), acc_dtype).at[plan.seg_ids].add(
-        prod, mode="drop", indices_are_sorted=True
-    )
+    with jax.named_scope("replay_scatter"):
+        return jnp.zeros((nnz_cap,), acc_dtype).at[plan.seg_ids].add(
+            prod, mode="drop", indices_are_sorted=True
+        )
 
 
 def lp_replay_values(plan: SpgemmPlan, a_values: jax.Array,
@@ -596,7 +603,8 @@ def resolve_plan(a: CSR, b: CSR, fm_cap: int, policy: str, cache, key=None):
     from repro.core.plan_cache import structure_key  # cycle-free late import
 
     if key is None:
-        key = structure_key(a, b, fm_cap, policy)
+        with span("plan.key"):
+            key = structure_key(a, b, fm_cap, policy)
     if cache is not None:
         plan = cache.get(key)
         if plan is not None:
@@ -713,8 +721,8 @@ def spgemm(a: CSR, b: CSR, method: str = "auto", compress: str = "auto",
 
     trace: None (default) | bool | "off" | "on" | "xprof" — phase tracing for
         this call (``repro.obs``): "on" records nesting spans
-        (``spgemm.prepare``, ``plan.build``, ``numeric.dispatch``, ...) for
-        Chrome trace-event export and feeds the per-phase latency histograms;
+        (``spgemm.prepare``, ``plan.key``, ``plan.build``,
+        ``numeric.dispatch``, ...) for Chrome trace-event export;
         "xprof" additionally wraps each span in
         ``jax.profiler.TraceAnnotation``. ``None`` defers to the ambient mode
         (ultimately ``$REPRO_TRACE``, mirroring how ``validate=None`` defers

@@ -21,7 +21,6 @@ from repro.obs.metrics import (
     Histogram,
     MetricsRegistry,
     default_registry,
-    observe,
     reset_metrics,
 )
 from repro.obs.recorder import (
@@ -70,7 +69,6 @@ __all__ = [
     "events",
     "export_chrome_trace",
     "new_trace_id",
-    "observe",
     "reset_metrics",
     "reset_obs",
     "reset_recorder",

@@ -6,9 +6,7 @@ One registry answers three kinds of question the counters alone could not:
     (1us .. ~18h) and reports p50/p95/p99 by in-bucket interpolation, clamped
     to the exact observed min/max so tails are never invented. This replaces
     the serving tier's lone EWMA: ``SparseService`` keeps real step- and
-    request-latency distributions, and ``obs.trace`` spans feed per-phase /
-    per-kernel histograms (``plan.build``, ``numeric.dispatch``,
-    ``numeric.dispatch[pallas]``, ...).
+    request-latency distributions (``serve.step``, ``serve.request``).
   * **Gauges** — live values read at export time (a plain number or a
     zero-arg callable), e.g. ``Heartbeat.write_errors`` surfaced mid-run
     instead of only at ``stop()``.
@@ -21,8 +19,8 @@ and ``to_prometheus()`` (text exposition format, names sanitized to
 ``repro_*``) — both pure renderings, no side effects on the metrics.
 
 Histogram observation is only ever driven from code that already decided to
-measure (an enabled span, the serving tier's step loop), so the registry
-adds nothing to the tracing-off replay hot path.
+measure (the serving tier's step loop), so the registry adds nothing to the
+replay hot path.
 """
 from __future__ import annotations
 
@@ -224,13 +222,8 @@ _DEFAULT = MetricsRegistry()
 
 
 def default_registry() -> MetricsRegistry:
-    """The process-wide registry spans and gauges feed by default."""
+    """The process-wide registry (gauges such as the heartbeat's feed it)."""
     return _DEFAULT
-
-
-def observe(name: str, seconds: float) -> None:
-    """Record one latency into the default registry's ``name`` histogram."""
-    _DEFAULT.observe(name, seconds)
 
 
 def reset_metrics() -> None:

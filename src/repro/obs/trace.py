@@ -10,8 +10,8 @@ per-phase timer hierarchy for the same reason). This module is that layer:
     phases of ``core/spgemm.py``, ``core/executor.py``, ``dist/executor.py``,
     ``kernels/ops.py`` and ``serve/spgemm_service.py``.
   * **Off by default, and off means OFF**: a disabled ``span()`` returns a
-    shared no-op context manager — no event, no timestamp, no histogram
-    observation, no counter bump — so the pinned-replay hot path stays
+    shared no-op context manager — no event, no timestamp, no counter
+    bump — so the pinned-replay hot path stays
     dispatch-identical to the untraced build (telemetry-asserted in
     tests/test_obs.py; priced in ``benchmarks.run --bench obs``).
   * Modes mirror ``$REPRO_VALIDATE``: ``spgemm(trace=...)`` takes
@@ -26,11 +26,12 @@ per-phase timer hierarchy for the same reason). This module is that layer:
   * ``export_chrome_trace(path)`` writes Chrome trace-event JSON ("X"
     complete events) loadable in chrome://tracing / Perfetto.
 
-Completed spans also feed ``obs.metrics`` latency histograms keyed by span
-name (plus a ``<name>[<kernel>]`` variant when the span carries a ``kernel``
-attr), which is where the per-phase / per-kernel p50/p95/p99 distributions
-come from. Spans time the *host side* of a dispatch — JAX async dispatch is
-never blocked on; device time belongs to the "xprof" mode's annotations.
+Spans time the *host side* of a phase: on an asynchronous dispatch
+(``numeric.dispatch``, ``dist.replay``) that is the enqueue, not the device
+work behind it, so spans feed no latency histogram. Device time belongs to
+the "xprof" mode's annotations and to the ``jax.named_scope`` names the
+jitted phases carry (listed beside ``SPAN_NAMES``), which a device profile
+records on each op.
 
 Single-threaded by design, like the serving tier: the span stack and the
 ambient trace id are plain module state, deterministic under the chaos
@@ -55,14 +56,21 @@ TRACE_ENV_VAR = "REPRO_TRACE"
 MAX_EVENTS = 100_000
 
 # The span taxonomy. Every ``span("...")`` literal in the stack must come
-# from this set — dashboards, the flight recorder, the latency histograms
+# from this set — dashboards, the flight recorder, the benchmark's readers
 # and the ROADMAP phase table all key on these exact strings, so a
-# free-typed name silently drops out of the phase-latency story. Enforced
+# free-typed name silently drops out of the phase story. Enforced
 # statically by ``python -m repro.analysis`` (rule ``span``); extend the
 # set (and the ROADMAP table) in the same commit that adds a new phase.
+#
+# Inside the jitted programs the phases are ``jax.named_scope``s instead,
+# recorded on each device op's ``tf_op`` path in a profile:
+# ``spgemm_expand``, ``spgemm_sort`` and ``spgemm_permute`` in
+# ``expand_and_sort``; ``replay_gather`` and ``replay_scatter`` in
+# ``numeric_reuse`` (core/spgemm.py).
 SPAN_NAMES = frozenset({
-    "spgemm.prepare",     # operand normalization + structure hash
+    "spgemm.prepare",     # operand repad + the f_m scalars
     "spgemm.symbolic",    # symbolic phase: sizes + plan expansion
+    "plan.key",           # structure hash (copies both operands to the host)
     "plan.build",         # plan assembly (sort, seg ids, slot maps)
     "numeric.dispatch",   # executor-level replay dispatch
     "numeric.kernel",     # one numeric kernel execution
@@ -172,8 +180,8 @@ _NOOP = _Noop()
 
 
 class _Span:
-    """One live span: records a Chrome "X" event + a histogram observation on
-    exit. Only ever constructed when tracing is enabled."""
+    """One live span: records a Chrome "X" event on exit. Only ever
+    constructed when tracing is enabled."""
 
     __slots__ = ("name", "attrs", "_start", "_annotation")
 
@@ -225,12 +233,6 @@ class _Span:
             })
         else:
             _STATE.dropped += 1
-        from repro.obs import metrics  # lazy: metrics pulls telemetry
-
-        metrics.observe(self.name, dur_s)
-        kernel = self.attrs.get("kernel")
-        if kernel is not None:
-            metrics.observe(f"{self.name}[{kernel}]", dur_s)
         return False
 
 
@@ -238,9 +240,8 @@ def span(name: str, **attrs):
     """Open a phase span: ``with span("plan.build", fm_cap=cap): ...``.
 
     Disabled tracing returns a shared no-op context manager — the call costs
-    one mode check and nothing else (no event, no clock read, no histogram).
-    Attrs land in the exported event's ``args``; a ``kernel=`` attr
-    additionally routes the duration into that kernel's histogram.
+    one mode check and nothing else (no event, no clock read). Attrs land in
+    the exported event's ``args``.
     """
     if not enabled():
         return _NOOP

@@ -617,6 +617,17 @@ def test_real_repo_has_no_new_findings():
                                                    "obs/trace.py"}
 
 
+def test_span_rule_accepts_the_structure_hash_span():
+    """``plan.key`` (``resolve_plan``'s structure hash) is in the taxonomy,
+    and the ``span`` rule passes the real tree that opens it."""
+    from repro.obs.trace import SPAN_NAMES
+
+    assert "plan.key" in SPAN_NAMES
+    assert 'span("plan.key")' in (REAL_ROOT / "core" / "spgemm.py").read_text()
+    report = run_analysis(REAL_ROOT, rules=["span"])
+    assert report.ok and not report.new, [f.render() for f in report.new]
+
+
 def test_real_repo_scans_all_modules():
     report = run_analysis(REAL_ROOT)
     assert report.stats["modules"] > 60
