@@ -90,6 +90,13 @@ def compress_matrix(b: CSR, nnz_cap: int | None = None) -> CompressedMatrix:
     return CompressedMatrix(indptr=indptr, csi=out_csi, cs=out_cs, shape=b.shape)
 
 
+def per_slot_products(a: CSR, b_row_nnz: jax.Array) -> jax.Array:
+    """Products each A slot makes: the size of the B row its column names
+    (0 on padding slots). Sums to f_m; the expansion lays them out."""
+    j = jnp.minimum(a.indices, b_row_nnz.shape[0] - 1)
+    return jnp.where(a.valid_mask(), b_row_nnz[j], 0)
+
+
 @jax.jit
 def flops_stats(a: CSR, b_row_nnz: jax.Array):
     """(f_m total, per-row flops, MAXRF) for C = A*B given B's row sizes.
@@ -98,8 +105,7 @@ def flops_stats(a: CSR, b_row_nnz: jax.Array):
     used to size L2 accumulator chunks (memory pool CHUNKSIZE).
     """
     rows = csr_row_ids(a.indptr, a.nnz_cap)
-    valid = a.valid_mask()
-    contrib = jnp.where(valid, b_row_nnz[jnp.minimum(a.indices, b_row_nnz.shape[0] - 1)], 0)
+    contrib = per_slot_products(a, b_row_nnz)
     row_flops = jnp.zeros((a.m,), jnp.int64 if contrib.dtype == jnp.int64 else jnp.int32)
     row_flops = row_flops.at[rows].add(contrib, mode="drop")
     return jnp.sum(row_flops), row_flops, jnp.max(row_flops)

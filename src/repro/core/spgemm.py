@@ -29,10 +29,11 @@ stages are:
   ``plan_from_sorted`` (jit, static nnz_cap) -> SpgemmPlan (v2, precomposed)
   ``numeric_reuse``    (jit)                 -> C values
 
-The plan is *precomposed* (v2): ``plan_from_sorted`` folds the sort
-permutation into the slot maps at build time (``a_slot_s = a_slot[order]``,
-``b_slot_s = b_slot[order]``) and folds validity into sentinel ``seg_ids``
-(padding products point at slot ``nnz_cap`` and are dropped by the scatter).
+The plan is *precomposed* (v2): ``expand_and_sort`` folds the sort
+permutation into the slot maps (``a_slot_s = a_slot[order]``,
+``b_slot_s = b_slot[order]``) and ``plan_from_sorted`` folds validity into
+sentinel ``seg_ids`` (padding products point at slot ``nnz_cap`` and are
+dropped by the scatter).
 A numeric replay is therefore two gathers + one ``indices_are_sorted``
 segment-sum — no O(f_m) permutation pass, no mask — and accumulates in
 ``jnp.result_type(a_values, b_values)`` so mixed-precision operands never
@@ -73,6 +74,7 @@ from repro.core.compression import (
     compress_matrix,
     compression_decision,
     flops_stats,
+    per_slot_products,
 )
 from repro.core.meta import DEFAULT_PAD_POLICY, round_capacity
 from repro.core.utils import popcount, segmented_scan, segment_ends
@@ -112,20 +114,19 @@ class SortedExpansion(NamedTuple):
     """One expansion + one sort: everything both phases need.
 
     Produced by ``expand_and_sort``; consumed by the host (``row_sizes`` ->
-    nnz(C)) and by ``plan_from_sorted`` (everything else). ``heads`` marks the
+    nnz(C)) and by ``plan_from_sorted`` (everything). Every product-long
+    field is in sorted order, so the plan build needs no permutation and
+    holds nothing beside the plan that it does not read. ``heads`` marks the
     first product of each distinct (row, col) group in sorted order;
     ``seg_ids`` maps each sorted product to its C slot.
     """
 
-    order: jax.Array  # (fm_cap,) int32 — the single sort permutation
-    rows_s: jax.Array  # (fm_cap,) int32 — rows in sorted order
     cols_s: jax.Array  # (fm_cap,) int32 — cols in sorted order
     valid_s: jax.Array  # (fm_cap,) bool — validity in sorted order
     heads: jax.Array  # (fm_cap,) bool — group heads (padding mints none)
     seg_ids: jax.Array  # (fm_cap,) int32 — sorted product -> C slot
-    a_slot: jax.Array  # (fm_cap,) int32 — unsorted, from the expansion
-    b_slot: jax.Array  # (fm_cap,) int32
-    valid: jax.Array  # (fm_cap,) bool
+    a_slot_s: jax.Array  # (fm_cap,) int32 — A slot per sorted product
+    b_slot_s: jax.Array  # (fm_cap,) int32 — B slot per sorted product
     row_sizes: jax.Array  # (m,) int32 — the symbolic output
 
 
@@ -182,31 +183,60 @@ def _single_sort_order(rows: jax.Array, keys: jax.Array, m: int,
     return order
 
 
+def _spread_over_segments(v: jax.Array, starts: jax.Array,
+                          fm_cap: int) -> jax.Array:
+    """``v[max{i : starts[i] <= t}]`` for every t < fm_cap (``starts``
+    non-decreasing, ``starts[0] == 0``).
+
+    Scatters each slot's difference from the slot before at its segment
+    start, then takes a prefix sum: O(len(v)) updates and one O(fm_cap) scan,
+    no search and no gather over fm_cap. Int32 wrap-around in the
+    differences telescopes exactly; starts at or past fm_cap are dropped.
+    """
+    diff = v - jnp.concatenate([jnp.zeros((1,), jnp.int32), v[:-1]])
+    marks = jnp.zeros((fm_cap,), jnp.int32).at[starts].add(
+        diff, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(marks, dtype=jnp.int32)
+
+
+def _expand_slots(a: CSR, row_nnz: jax.Array, indptr: jax.Array,
+                  slot_cap: int, fm_cap: int):
+    """Product t -> (A slot, slot in B's buffer, C row, valid) against a B
+    given by its row counts, row pointers and buffer cap ``slot_cap``.
+
+    Products of A slot i occupy [starts[i], starts[i] + per_slot[i]); every
+    quantity keyed by the A slot is spread over its segment by
+    ``_spread_over_segments``. Products past f_m (padding) get the last A
+    slot.
+    """
+    j = jnp.minimum(a.indices, indptr.shape[0] - 2)
+    per_slot = per_slot_products(a, row_nnz).astype(jnp.int32)
+    ends = jnp.cumsum(per_slot, dtype=jnp.int32)
+    starts = ends - per_slot
+    t = jnp.arange(fm_cap, dtype=jnp.int32)
+    a_slot = _spread_over_segments(
+        jnp.arange(a.nnz_cap, dtype=jnp.int32), starts, fm_cap)
+    # B slot = B row start + (t - segment start): the two per-slot terms
+    # are spread as one
+    b_slot = (t + _spread_over_segments(indptr[j] - starts, starts, fm_cap)
+              ).clip(0, slot_cap - 1)
+    rows = _spread_over_segments(
+        csr_row_ids(a.indptr, a.nnz_cap), starts, fm_cap)
+    return a_slot, b_slot, rows, t < ends[-1]
+
+
 @partial(jax.jit, static_argnames=("fm_cap",))
 def expand_products(a: CSR, b: CSR, fm_cap: int) -> ProductExpansion:
     """Enumerate all f_m multiplications with static capacity ``fm_cap``.
 
-    For product t: binary-search the owning A-slot in the exclusive prefix of
-    per-A-slot product counts, then offset into B's row. Fully vectorized.
+    Product t's A slot, C row and B slot come from per-A-slot values spread
+    over each slot's segment of products by a scatter at the segment starts
+    and a prefix sum (``_expand_slots``); the one gather over fm_cap reads
+    B's column. Fully vectorized, no loop.
     """
     _note_trace("expand_products")
-    b_row_nnz = b.row_nnz()
-    a_valid = a.valid_mask()
-    per_slot = jnp.where(
-        a_valid, b_row_nnz[jnp.minimum(a.indices, b.m - 1)], 0
-    ).astype(jnp.int32)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(per_slot).astype(jnp.int32)]
-    )  # (nnzA+1,)
-    t = jnp.arange(fm_cap, dtype=jnp.int32)
-    a_slot = (
-        jnp.searchsorted(offsets, t, side="right").astype(jnp.int32) - 1
-    ).clip(0, a.nnz_cap - 1)
-    within = t - offsets[a_slot]
-    valid = t < offsets[-1]
-    j = a.indices[a_slot]
-    b_slot = (b.indptr[jnp.minimum(j, b.m - 1)] + within).clip(0, b.nnz_cap - 1)
-    rows = csr_row_ids(a.indptr, a.nnz_cap)[a_slot]
+    a_slot, b_slot, rows, valid = _expand_slots(
+        a, b.row_nnz(), b.indptr, b.nnz_cap, fm_cap)
     col = b.indices[b_slot]
     return ProductExpansion(
         row=jnp.where(valid, rows, a.m),  # pad rows to m -> sorts to the end
@@ -223,7 +253,7 @@ def expand_and_sort(a: CSR, b: CSR, fm_cap: int) -> SortedExpansion:
 
     Returns sorted products plus per-row distinct-column counts — the
     symbolic phase's answer — so the driver never expands or sorts again for
-    the numeric plan.
+    the numeric plan, and the plan's slot maps already in sorted order.
     """
     _note_trace("expand_and_sort")
     # the named scopes mark each phase's device ops in a profile (their
@@ -247,16 +277,18 @@ def expand_and_sort(a: CSR, b: CSR, fm_cap: int) -> SortedExpansion:
         row_sizes = jnp.zeros((a.m,), jnp.int32).at[jnp.minimum(rows_s, a.m - 1)].add(
             heads.astype(jnp.int32), mode="drop"
         )
+    # the plan's slot maps, composed with the order here so that the plan
+    # build holds neither the order nor the unsorted maps beside the plan;
+    # outside the scopes, which name the symbolic phase's own work
+    a_slot_s = ex.a_slot[order]
+    b_slot_s = ex.b_slot[order]
     return SortedExpansion(
-        order=order,
-        rows_s=rows_s,
         cols_s=cols_s,
         valid_s=valid_s,
         heads=heads,
         seg_ids=seg_ids,
-        a_slot=ex.a_slot,
-        b_slot=ex.b_slot,
-        valid=ex.valid,
+        a_slot_s=a_slot_s,
+        b_slot_s=b_slot_s,
         row_sizes=row_sizes,
     )
 
@@ -265,9 +297,11 @@ def expand_and_sort(a: CSR, b: CSR, fm_cap: int) -> SortedExpansion:
 def plan_from_sorted(sx: SortedExpansion, k: int, nnz_cap: int) -> SpgemmPlan:
     """Back half of a fresh multiply: C structure + reuse plan, no re-sort.
 
-    Precomposes the sort permutation into the slot maps (plan v2): the one
-    extra gather pair here is paid once per *structure*, saving one O(f_m)
-    permutation gather on every numeric replay.
+    The slot maps come precomposed with the sort permutation (plan v2):
+    ``expand_and_sort`` pays that gather pair once per *structure*, saving
+    one O(f_m) permutation gather on every numeric replay. The plan's maps
+    are copies of ``sx``'s (a jitted output never shares an argument's
+    buffer).
     """
     _note_trace("plan_from_sorted")
     m = sx.row_sizes.shape[0]
@@ -281,8 +315,8 @@ def plan_from_sorted(sx: SortedExpansion, k: int, nnz_cap: int) -> SpgemmPlan:
         indptr=indptr,
         indices=c_indices,
         seg_ids=jnp.where(sx.valid_s, sx.seg_ids, nnz_cap),  # padded -> dropped
-        a_slot_s=sx.a_slot[sx.order],
-        b_slot_s=sx.b_slot[sx.order],
+        a_slot_s=sx.a_slot_s,
+        b_slot_s=sx.b_slot_s,
         shape=(m, k),
     )
 
@@ -334,25 +368,9 @@ def symbolic_compressed(a: CSR, bc: CompressedMatrix, m: int, fm_cap: int,
     key_bound: static bound on CSI values (ceil(k/32)) enabling the packed
     single-key sort; None falls back to the fused multi-key sort."""
     _note_trace("symbolic_compressed")
-    bc_row_nnz = bc.row_nnz()
-    a_valid = a.valid_mask()
-    nb = bc.indptr.shape[0] - 1
-    per_slot = jnp.where(
-        a_valid, bc_row_nnz[jnp.minimum(a.indices, nb - 1)], 0
-    ).astype(jnp.int32)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(per_slot).astype(jnp.int32)]
-    )
-    t = jnp.arange(fm_cap, dtype=jnp.int32)
-    a_slot = (
-        jnp.searchsorted(offsets, t, side="right").astype(jnp.int32) - 1
-    ).clip(0, a.nnz_cap - 1)
-    within = t - offsets[a_slot]
-    valid = t < offsets[-1]
-    j = jnp.minimum(a.indices[a_slot], nb - 1)
-    cap = bc.csi.shape[0]
-    b_slot = (bc.indptr[j] + within).clip(0, cap - 1)
-    rows = jnp.where(valid, csr_row_ids(a.indptr, a.nnz_cap)[a_slot], m)
+    _, b_slot, rows, valid = _expand_slots(
+        a, bc.row_nnz(), bc.indptr, bc.csi.shape[0], fm_cap)
+    rows = jnp.where(valid, rows, m)
     keys = jnp.where(valid, bc.csi[b_slot], 0)
     cs = jnp.where(valid, bc.cs[b_slot], jnp.uint32(0))
     return _symbolic_sorted(rows, keys, cs, valid, m, fm_cap, key_bound=key_bound)
@@ -539,7 +557,7 @@ def symbolic(a: CSR, b: CSR, compress: str = "auto",
             use_c = True
     stats["cf"], stats["cmrf"], stats["compressed"] = cf, cmrf, use_c
     if use_c and bc is not None:
-        fm_c = max(int(jnp.sum(_per_slot(a, bc.row_nnz(), bc.indptr.shape[0] - 1))), 1)
+        fm_c = max(int(flops_stats(a, bc.row_nnz())[0]), 1)
         cap = round_capacity(fm_c, pad_policy)
         sizes = symbolic_compressed(a, bc, a.m, cap, key_bound=-(-b.k // 32))
     else:
@@ -858,8 +876,3 @@ def _fm_scalars(a: CSR, b: CSR):
     fm, _, maxrf = flops_stats(a, b.row_nnz())
     return fm, maxrf
 
-
-@jax.jit
-def _per_slot(a: CSR, row_nnz: jax.Array, nb: int):
-    valid = a.valid_mask()
-    return jnp.where(valid, row_nnz[jnp.minimum(a.indices, nb - 1)], 0)

@@ -417,15 +417,18 @@ PLAN_SCOPES = ("spgemm_expand", "spgemm_sort", "spgemm_permute")
 REPLAY_SCOPES = ("replay_gather", "replay_scatter")
 
 
-def _op_names(compiled_text: str, opcode: str) -> list:
+def _op_names(compiled_text: str, opcode: str, rows: int | None = None) -> list:
     """The ``op_name`` metadata of every ``opcode`` instruction in an
-    optimized HLO text."""
+    optimized HLO text; with ``rows``, of those whose result has that
+    leading dimension."""
     import re
 
     call = re.compile(r"[\]})] %s\(" % opcode)
     name = re.compile(r'op_name="([^"]*)"')
+    lead = re.compile(r"= \w+\[%s\b" % (r"\d+" if rows is None else rows))
     return [name.search(line).group(1) for line in compiled_text.splitlines()
-            if call.search(line) and name.search(line)]
+            if call.search(line) and name.search(line)
+            and (rows is None or lead.search(line))]
 
 
 @pytest.mark.parametrize("scope", PLAN_SCOPES)
@@ -450,17 +453,24 @@ def test_numeric_reuse_lowers_with_its_phase_scopes(ab, scope):
 
 @pytest.mark.parametrize("k", [40, 2**30])  # one packed key; three keys
 def test_each_heavy_op_lies_in_its_phase_scope(ab, k):
-    """The ops the benchmark's scope metrics time: the searchsorted loop
-    under ``spgemm_expand``, the sort under ``spgemm_sort``, the replay's
-    gathers and scatter under theirs (optimized HLO on the CPU)."""
+    """The ops the benchmark's scope metrics time: the expansion's scatters
+    and its one gather over the products under ``spgemm_expand``, with no
+    loop anywhere in the plan build's front half, the sort under
+    ``spgemm_sort``, the replay's gathers and scatter under theirs
+    (optimized HLO on the CPU)."""
     from repro.core.spgemm import expand_and_sort, numeric_reuse
     from repro.sparse.formats import CSR
 
     a, b = ab
     wide = CSR(b.indptr, b.indices, b.values, shape=(b.m, k))
-    text = expand_and_sort.lower(a, wide, fm_cap=256).compile().as_text()
-    whiles = _op_names(text, "while")
-    assert whiles and all("/spgemm_expand/" in n for n in whiles)
+    fm_cap = 256
+    text = expand_and_sort.lower(a, wide, fm_cap=fm_cap).compile().as_text()
+    assert not _op_names(text, "while")
+    spread = _op_names(text, "scatter", rows=fm_cap)
+    assert spread and all("/spgemm_expand/" in n for n in spread)
+    over_products = [n for n in _op_names(text, "gather", rows=fm_cap)
+                     if "/spgemm_expand/" in n]
+    assert len(over_products) == 1
     sorts = _op_names(text, "sort")
     assert sorts and all("/spgemm_sort/" in n for n in sorts)
     plan = spgemm(a, b, method="sparse", plan_cache=False).plan

@@ -1,8 +1,10 @@
 """Core SpGEMM tests: two-phase vs Gustavson oracle, compression rules,
 reuse semantics, meta-algorithm — including hypothesis property tests."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend.core import Var
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (
@@ -18,8 +20,12 @@ from repro.core import (
     symbolic_dense_bitmask,
     bitmask_rows,
     choose_method,
+    expand_and_sort,
+    expand_products,
+    plan_from_sorted,
 )
 from repro.core.meta import DENSE_K_CUTOFF
+from repro.core.spgemm import _repad_csr
 from repro.sparse import (
     CSR,
     banded_csr,
@@ -187,3 +193,99 @@ def test_flops_stats():
     _, _, _, rf = gustavson_numpy(a, b)
     np.testing.assert_array_equal(np.asarray(row_flops), rf)
     assert int(fm) == rf.sum() and int(maxrf) == rf.max()
+
+
+# --------------------------------------------------------------------------
+# The expansion against a binary search over the product offsets
+# --------------------------------------------------------------------------
+
+
+def _expand_by_search(a, b, fm_cap):
+    """Product t -> (row, col, A slot, B slot, valid) by a binary search of
+    t in the offsets of each A slot's products, in numpy: the reference the
+    scatter-and-prefix-sum expansion must match bit for bit."""
+    a_ip, a_ix = np.asarray(a.indptr), np.asarray(a.indices)
+    b_ip, b_ix = np.asarray(b.indptr), np.asarray(b.indices)
+    live = np.arange(a.nnz_cap) < a_ip[-1]
+    j = np.minimum(a_ix, b.m - 1)
+    per_slot = np.where(live, np.diff(b_ip)[j], 0)
+    offsets = np.concatenate([[0], np.cumsum(per_slot)])
+    t = np.arange(fm_cap)
+    a_slot = (np.searchsorted(offsets, t, side="right") - 1).clip(
+        0, a.nnz_cap - 1)
+    valid = t < offsets[-1]
+    b_slot = (b_ip[j[a_slot]] + t - offsets[a_slot]).clip(0, b.nnz_cap - 1)
+    a_row = np.minimum(np.searchsorted(a_ip[1:], np.arange(a.nnz_cap),
+                                       side="right"), a.m - 1)
+    return dict(row=np.where(valid, a_row[a_slot], a.m),
+                col=np.where(valid, b_ix[b_slot], 0),
+                a_slot=a_slot, b_slot=b_slot, valid=valid)
+
+
+def _empty_first_b_row():
+    """A's first slot reads B's row 0, which is empty; A's row 2 is empty."""
+    a = np.zeros((5, 5), np.float32)
+    a[0, [0, 2]] = 1.0
+    a[1, [0, 1]] = 2.0
+    a[3, 4] = 3.0
+    a[4, [0, 3]] = 4.0
+    b = np.zeros((5, 5), np.float32)
+    b[1, [0, 3]] = 1.0
+    b[2, 1] = 2.0
+    b[3, [0, 2, 4]] = 3.0
+    b[4, 4] = 4.0
+    return CSR.from_dense(a), CSR.from_dense(b)
+
+
+EXPANSION_CASES = {
+    "stencil16": lambda: (stencil2d_csr(16, 16), stencil2d_csr(16, 16)),
+    "rmat_s8_ef16": lambda: (rmat_csr(8, 16, 0), rmat_csr(8, 16, 0)),
+    "random64_empty_rows": lambda: (random_csr(64, 64, 1.0, 21),
+                                    random_csr(64, 64, 1.0, 22)),
+    "empty_first_b_row": _empty_first_b_row,
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPANSION_CASES))
+@pytest.mark.parametrize("a_padding", [0, 13])
+@pytest.mark.parametrize("fm_extra", [0, 29])
+def test_expansion_matches_binary_search(case, a_padding, fm_extra):
+    """``expand_products`` equals the binary-search expansion field by field,
+    bitwise, padding products included: with A exact or carrying padding
+    slots, and with fm_cap equal to f_m or above it."""
+    a, b = EXPANSION_CASES[case]()
+    if case == "random64_empty_rows":
+        assert (np.diff(np.asarray(a.indptr)) == 0).any()
+        assert (np.diff(np.asarray(b.indptr)) == 0).any()
+    if case == "empty_first_b_row":
+        assert int(b.indptr[1]) == 0 and int(a.indices[0]) == 0
+    nnz = int(a.indptr[-1])
+    assert a.nnz_cap == nnz
+    a = _repad_csr(a, nnz + a_padding)
+    fm = int(flops_stats(a, b.row_nnz())[0])
+    fm_cap = fm + fm_extra
+    got = expand_products(a, b, fm_cap)
+    want = _expand_by_search(a, b, fm_cap)
+    for field, ref in want.items():
+        out = np.asarray(getattr(got, field))
+        assert out.dtype == (np.bool_ if field == "valid" else np.int32), field
+        np.testing.assert_array_equal(out, ref, err_msg=field)
+
+
+def test_plan_build_reads_every_field_of_the_expansion():
+    """``expand_and_sort`` returns only what ``plan_from_sorted`` reads: the
+    plan build holds the whole expansion beside the plan, so a field it
+    does not read is device memory held for nothing at the peak."""
+    a = stencil2d_csr(8, 8)
+    fm_cap = int(flops_stats(a, a.row_nnz())[0])
+    sx = expand_and_sort(a, a, fm_cap)
+    nnz_cap = int(np.asarray(sx.row_sizes).sum())
+    outer = jax.make_jaxpr(
+        lambda sx: plan_from_sorted(sx, a.k, nnz_cap))(sx).jaxpr
+    (call,) = outer.eqns
+    inner = call.params["jaxpr"].jaxpr
+    read = {v for e in inner.eqns for v in e.invars
+            if isinstance(v, Var)}
+    read |= {v for v in inner.outvars if isinstance(v, Var)}
+    unread = [f for f, v in zip(sx._fields, inner.invars) if v not in read]
+    assert unread == []

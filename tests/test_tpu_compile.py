@@ -8,6 +8,7 @@ the chip's 16 GB. The topology is described inside a fixture, never at import
 or collection, so every test worker collects the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.executor import _apply_batched
-from repro.core.spgemm import (SpgemmPlan, expand_and_sort, numeric_reuse,
-                               plan_from_sorted)
+from repro.core.spgemm import (SpgemmPlan, expand_and_sort, expand_products,
+                               numeric_reuse, plan_from_sorted)
 from repro.kernels import limits
 from repro.kernels.segsum_reuse import segsum_reuse_arrays
 from repro.kernels.spgemm_lp import lp_reuse_arrays, spgemm_lp
@@ -98,6 +99,18 @@ def test_plan_build_fits_one_chip(shape, deployment):
             + build.memory_analysis().output_size_in_bytes
             + build.memory_analysis().temp_size_in_bytes)
     assert peak <= HBM_BYTES
+
+
+@pytest.mark.parametrize("deployment", sorted(SMOKE_SIZES))
+def test_expansion_compiles_without_a_loop(shape, deployment):
+    """The expansion finds each product's A slot by scatter and prefix sum:
+    the chip's program holds no ``while`` (a binary search over the
+    products would be one, a gather over all of them per bisection step)."""
+    m, a_cap, fm_cap, _ = SMOKE_SIZES[deployment]
+    a = _csr(shape, m, a_cap)
+    text = expand_products.lower(a, a, fm_cap=fm_cap).compile().as_text()
+    assert not re.search(r"[\]})] while\(", text)
+    assert re.search(r"[\]})] scatter\(", text)
 
 
 @pytest.mark.parametrize("deployment", sorted(SMOKE_SIZES))
